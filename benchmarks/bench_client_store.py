@@ -95,7 +95,9 @@ def _worker() -> None:
 
 def run(quick: bool = True):
     """Spawn the 8-device worker, collect the report, emit CSV rows."""
-    env = dict(os.environ,
+    # 8 forced host devices, pinned to the CPU: the parent already holds the
+    # accelerator, and a second process asking for it would fail or hang
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                BENCH_QUICK="1" if quick else "0")
     out = subprocess.run(

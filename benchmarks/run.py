@@ -15,6 +15,7 @@ from benchmarks import (bench_async_engine, bench_client_store,
                         bench_roofline, bench_round_engine, fig1_quadratic,
                         fig3_bias_variance, fig4_ess, table1_client_cost,
                         table3_benchmark_sim, table3_lr_sim)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "table1": table1_client_cost,
@@ -39,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(BENCHES))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     names = list(BENCHES) if not args.only else args.only.split(",")
     print("name,us_per_call,derived")

@@ -17,8 +17,10 @@ per axpy). The kernel reshapes the step into two fused passes:
 VMEM tiling: blocks are (l_pad, TILE_D) for the history and (1, TILE_D) for
 the vectors, TILE_D a multiple of 128 lanes; l_pad is the static history
 capacity (samples per round are single digits, so the whole history column
-fits VMEM many times over). Validated against ``ref.py`` in interpret mode;
-TPU is the target, not the runtime.
+fits VMEM many times over). Pass A's scalars accumulate in one lane-padded
+(1, 128k) row that stays resident across the grid. Both kernels compile for
+TPU (``tests/test_tpu_compile.py``); ``interpret=True`` runs them on the CPU
+against ``ref.py``.
 """
 from __future__ import annotations
 
@@ -32,16 +34,27 @@ TILE_D = 512
 
 
 def _reduce_kernel(u_ref, delta_ref, v_ref, out_ref):
-    """out[0, :lp] = partial <v_k, u>; out[0, lp] = <u,u>; out[0, lp+1] = <u,delta>."""
-    u = u_ref[0, :].astype(jnp.float32)
-    delta = delta_ref[0, :].astype(jnp.float32)
+    """out[0, :lp] += <v_k, u>; out[0, lp] += <u,u>; out[0, lp+1] += <u,delta>.
+
+    The (1, out_w) output block is the same for every d-tile, so it stays
+    resident in VMEM and accumulates across the sequential grid. The row is
+    assembled with lane masks (no scalar stores into vector memory)."""
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    u = u_ref[...].astype(jnp.float32)              # (1, TILE_D)
+    delta = delta_ref[...].astype(jnp.float32)
     V = v_ref[...].astype(jnp.float32)              # (lp, TILE_D)
-    dots = jnp.sum(V * u[None, :], axis=1)          # (lp,)
-    uu = jnp.sum(u * u)
-    ud = jnp.sum(u * delta)
-    out_ref[0, : dots.shape[0]] = dots
-    out_ref[0, dots.shape[0]] = uu
-    out_ref[0, dots.shape[0] + 1] = ud
+    lp = V.shape[0]
+    dots = jnp.sum(V * u, axis=1, keepdims=True)    # (lp, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    row = jnp.where(lane == lp, jnp.sum(u * u, axis=1, keepdims=True), 0.0)
+    row += jnp.where(lane == lp + 1,
+                     jnp.sum(u * delta, axis=1, keepdims=True), 0.0)
+    for k in range(lp):
+        row += jnp.where(lane == k, dots[k:k + 1, :], 0.0)
+    out_ref[...] += row
 
 
 def _map_kernel(w_ref, s_ref, u_ref, delta_ref, v_ref, vout_ref, dout_ref):
@@ -66,7 +79,7 @@ def _pad_to(x, m, axis=-1):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dp_reduce(u, delta, V, *, interpret: bool = True):
+def dp_reduce(u, delta, V, *, interpret: bool = False):
     """Fused pass A. u, delta: (d,); V: (lp, d). Returns
     (dots (lp,), uu, ud) accumulated in fp32."""
     lp, d = V.shape
@@ -77,7 +90,7 @@ def dp_reduce(u, delta, V, *, interpret: bool = True):
     n_tiles = dp // TILE_D
     out_w = ((lp + 2 + 127) // 128) * 128
 
-    partials = pl.pallas_call(
+    totals = pl.pallas_call(
         _reduce_kernel,
         grid=(n_tiles,),
         in_specs=[
@@ -85,16 +98,15 @@ def dp_reduce(u, delta, V, *, interpret: bool = True):
             pl.BlockSpec((1, TILE_D), lambda i: (0, i)),
             pl.BlockSpec((lp, TILE_D), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((1, out_w), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, out_w), jnp.float32),
+        out_specs=pl.BlockSpec((1, out_w), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, out_w), jnp.float32),
         interpret=interpret,
-    )(u2, delta2, V2)
-    totals = jnp.sum(partials, axis=0)
+    )(u2, delta2, V2)[0]
     return totals[:lp], totals[lp], totals[lp + 1]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def dp_map(w, s, u, delta, V, *, interpret: bool = True):
+def dp_map(w, s, u, delta, V, *, interpret: bool = False):
     """Fused pass B. Returns (v_new (d,), delta_new (d,))."""
     lp, d = V.shape
     u2 = _pad_to(u[None, :], TILE_D)

@@ -1,8 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``interpret=True`` everywhere by default: this container is CPU-only and
-Pallas interpret mode executes the kernel bodies in Python for correctness
-validation; on real TPU hardware callers pass ``interpret=False``.
+The kernels compile for the TPU by default. ``interpret=True`` runs the
+kernel bodies through the Pallas interpreter instead, which is how the CPU
+tests check them against ``ref.py``; nothing chooses it implicitly.
 """
 from __future__ import annotations
 
@@ -16,7 +16,7 @@ from repro.kernels import swa_decode as _swa
 
 
 @functools.partial(jax.jit, static_argnames=("rho", "interpret"))
-def dp_step(u, delta, V, c_hist, t, *, rho: float, interpret: bool = True):
+def dp_step(u, delta, V, c_hist, t, *, rho: float, interpret: bool = False):
     """One fused Sherman-Morrison DP step (paper eqs. 22-23).
 
     u: (d,) = x_t - xbar_{t-1}; delta: (d,) = Delta~_{t-1};
@@ -39,7 +39,7 @@ def dp_step(u, delta, V, c_hist, t, *, rho: float, interpret: bool = True):
     return v, delta_new, a, c_new
 
 
-def dp_delta_flat(x0, samples, *, rho: float, interpret: bool = True):
+def dp_delta_flat(x0, samples, *, rho: float, interpret: bool = False):
     """Full Delta_hat_l from stacked (l, d) samples using the fused kernels —
     the kernel-path equivalent of ``repro.core.dp_delta.dp_delta`` on flat
     vectors. Python loop over the (static, single-digit) sample count."""
@@ -62,7 +62,7 @@ def dp_delta_flat(x0, samples, *, rho: float, interpret: bool = True):
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def swa_decode(q, cache_k, cache_v, slot_pos, pos, *, window: int = 0,
-               interpret: bool = True):
+               interpret: bool = False):
     """Sliding-window decode attention over a ring-buffer cache.
 
     q: (B, H, dh) one token's query heads; cache_k/v: (B, L, KV, dh);

@@ -42,14 +42,14 @@ def _swa_decode_kernel(pos_ref, window_ref,            # scalar prefetch
     q = q_ref[0, 0].astype(jnp.float32)                # (G, dh)
     k = k_ref[0].astype(jnp.float32)                   # (TILE_L, dh)
     v = v_ref[0].astype(jnp.float32)                   # (TILE_L, dh)
-    sp = slot_ref[...]                                 # (TILE_L,) int32
+    sp = slot_ref[...]                                 # (1, TILE_L) int32
     pos = pos_ref[0]
     window = window_ref[0]
 
     scores = jnp.dot(q, k.T) / jnp.sqrt(jnp.float32(q.shape[-1]))  # (G, T)
     valid = (sp >= 0) & (sp <= pos)
     valid = valid & ((window <= 0) | (sp > pos - window))
-    scores = jnp.where(valid[None, :], scores, NEG_INF)
+    scores = jnp.where(valid, scores, NEG_INF)
 
     m_prev = m_scr[...]                                # (G, 1)
     m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
@@ -68,7 +68,7 @@ def _swa_decode_kernel(pos_ref, window_ref,            # scalar prefetch
 @functools.partial(jax.jit,
                    static_argnames=("window", "interpret", "tile_l"))
 def swa_decode_attention(q, k, v, slot_pos, pos, *, window: int = 0,
-                         interpret: bool = True, tile_l: int = TILE_L):
+                         interpret: bool = False, tile_l: int = TILE_L):
     """q: (B, KV, G, dh); k, v: (B, L, KV, dh); slot_pos: (L,) int32;
     pos: scalar int32 (position of the new token). Returns (B, KV, G, dh).
 
@@ -95,7 +95,9 @@ def swa_decode_attention(q, k, v, slot_pos, pos, *, window: int = 0,
                              lambda b, h, l, *_: (b * KV + h, l, 0)),
                 pl.BlockSpec((1, tile_l, dh),
                              lambda b, h, l, *_: (b * KV + h, l, 0)),
-                pl.BlockSpec((tile_l,), lambda b, h, l, *_: (l,)),
+                # 2-D (1, L): a 1-D int32 block's Mosaic tiling disagrees
+                # with the layout XLA gives the operand
+                pl.BlockSpec((1, tile_l), lambda b, h, l, *_: (0, l)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, dh),
                                    lambda b, h, l, *_: (b, h, 0, 0)),
@@ -107,4 +109,4 @@ def swa_decode_attention(q, k, v, slot_pos, pos, *, window: int = 0,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, dh), q.dtype),
         interpret=interpret,
-    )(pos_s, win_s, q, ktf, vtf, slot_pos)
+    )(pos_s, win_s, q, ktf, vtf, slot_pos.reshape(1, L))

@@ -6,8 +6,8 @@ dispatches at runtime — the fused round (``make_fed_round``) on the
 window=1 path — so a config that compiles in the dry-run runs in the
 unified loop.
 
-The two ``os.environ`` statements below MUST stay ahead of every other
-import: jax locks the device count on first initialization, and the
+The ``os.environ`` statements below MUST stay ahead of every other import:
+jax locks the platform and device count on first initialization, and the
 dry-run needs 512 placeholder host devices for ``jax.make_mesh`` to build
 the production meshes. Tests override via REPRO_XLA_FLAGS.
 
@@ -17,6 +17,9 @@ Usage:
 """
 import os
 
+# placeholder host devices only: pinned to the CPU so the dry-run never
+# claims (or builds its mesh from) a real accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     os.environ.get("REPRO_XLA_FLAGS")
     or "--xla_force_host_platform_device_count=512"
@@ -41,8 +44,7 @@ from repro.launch.specs import (client_axes, input_specs,     # noqa: E402
                                 store_population_layout)
 from repro.models.steps import prefill_step, serve_step       # noqa: E402
 from repro.sharding import axis_rules                         # noqa: E402
-from repro.sharding.hlo_cost import (analyze as hlo_analyze,  # noqa: E402
-                                     xla_cost_analysis)
+from repro.sharding.hlo_cost import analyze as hlo_analyze   # noqa: E402
 from repro.sharding.roofline import derive, format_table      # noqa: E402
 
 
@@ -267,7 +269,7 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     # XLA's cost_analysis counts loop bodies once (no trip scaling) — see
     # EXPERIMENTS.md §Roofline/Methodology. Use the trip-count-aware HLO
     # walker for the real per-device numbers; keep XLA's raw view on record.
-    raw_cost = xla_cost_analysis(compiled)
+    raw_cost = compiled.cost_analysis()
     rec["cost_xla_raw"] = {k: raw_cost[k] for k in ("flops", "bytes accessed")
                            if k in raw_cost}
     hlo_text = compiled.as_text()

@@ -11,7 +11,7 @@ from typing import Optional
 import jax
 
 from repro.configs.base import MULTI_POD, SINGLE_POD, MeshConfig
-from repro.sharding import make_mesh_compat
+from repro.sharding import make_mesh
 
 
 def init_distributed(coordinator: Optional[str] = None,
@@ -67,12 +67,12 @@ def make_production_mesh(*, multi_pod: bool = False):
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (launch/dryrun.py does this)"
         )
-    return make_mesh_compat(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def mesh_from_config(mc: MeshConfig):
     """Materialize a ``MeshConfig`` as a jax mesh over the visible devices."""
-    return make_mesh_compat(mc.shape, mc.axes)
+    return make_mesh(mc.shape, mc.axes)
 
 
 def mesh_config(multi_pod: bool = False) -> MeshConfig:
@@ -83,4 +83,4 @@ def mesh_config(multi_pod: bool = False) -> MeshConfig:
 def make_host_mesh():
     """Whatever devices exist, as a 1D ("data",) mesh — CPU simulation."""
     n = jax.device_count()
-    return make_mesh_compat((n,), ("data",))
+    return make_mesh((n,), ("data",))

@@ -3,7 +3,7 @@ decode tokens with the cached state — the decode_32k/long_500k code path at
 CPU-friendly scale.
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-27b --smoke \
-      --batch 4 --prompt-len 96 --gen 32 [--pallas]
+      --batch 4 --prompt-len 96 --gen 32 [--pallas [--pallas-interpret]]
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import numpy as np
 
 from repro import configs
 from repro.data import SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params, prefill_step, serve_step
 
 
@@ -32,9 +33,13 @@ def main():
     ap.add_argument("--temperature", type=float, default=1.0)
     ap.add_argument("--pallas", action="store_true",
                     help="route decode attention through the Pallas "
-                         "swa_decode kernel (interpret mode on CPU)")
+                         "swa_decode kernel, compiled for the TPU")
+    ap.add_argument("--pallas-interpret", action="store_true",
+                    help="run that kernel in the Pallas interpreter instead "
+                         "(a CPU host has no compiled Pallas)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke \
         else configs.get_config(args.arch)
@@ -66,7 +71,8 @@ def main():
 
     step = jax.jit(lambda p, t, s, r: serve_step(
         p, t, s, cfg, sample=args.sample, rng=r,
-        temperature=args.temperature, use_pallas=args.pallas))
+        temperature=args.temperature, use_pallas=args.pallas,
+        pallas_interpret=args.pallas_interpret))
     out = [np.asarray(tok)]
     rng = jax.random.PRNGKey(args.seed + 1)
     t1 = time.time()

@@ -1,8 +1,10 @@
 """Federated LM training driver (end-to-end example entry point).
 
 Runs real federated rounds of the selected architecture on whatever devices
-exist (CPU simulation here; the same code paths the dry-run lowers for the
-production mesh). FedPA vs FedAvg is a flag; checkpoints + metrics logged.
+exist (a TPU chip, or the CPU at ``--smoke`` size; the same code paths the
+dry-run lowers for the production mesh). FedPA vs FedAvg is a flag;
+checkpoints + metrics logged. ``chip_smoke.py`` drives ``main`` in-process
+at fedlm-100m's full width.
 
   PYTHONPATH=src python -m repro.launch.train --arch fedlm-100m --smoke \
       --rounds 20 --algorithm fedpa
@@ -41,6 +43,7 @@ from repro.data import SyntheticLMData
 from repro.data.cohort_source import CohortSource
 from repro.data.prefetch import (globalize_cohort_batches, local_row_range,
                                  replicate_global)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import init_distributed, make_host_mesh
 from repro.models import init_params, lm_loss
 from repro.optim import get_optimizer
@@ -248,9 +251,13 @@ def restore_if_present(args, state, store, ckpt_tree):
     return state, start_round
 
 
-def main():
-    """Parse flags, build the round programs, drive the training loop."""
-    args = parse_args()
+def main(argv=None):
+    """Parse flags, build the round programs, drive the training loop.
+
+    Returns ``(state, store)``: the final server state and the client-state
+    store (None for stateless algorithms)."""
+    args = parse_args(argv)
+    enable_compile_cache()
     # before ANY jax device use: distributed init must see an
     # uninitialized backend
     distributed = init_distributed(args.coordinator, args.process_id,
@@ -270,7 +277,7 @@ def main():
     pop_mesh = make_host_mesh() if shard_pop else None
     is_main = jax.process_index() == 0
     if is_main:
-        print(f"arch={cfg.name} params={configs.get_smoke(args.arch).param_count() if args.smoke else cfg.param_count():,} "
+        print(f"arch={cfg.name} params={cfg.param_count():,} "
               f"algorithm={fed.algorithm} rounds={args.rounds}"
               + (f" processes={jax.process_count()}" if distributed else "")
               + (f" population_mesh={tuple(pop_mesh.shape.values())}"
@@ -370,6 +377,7 @@ def main():
                        q_chunk, pop_mesh=pop_mesh)
     if logf:
         logf.close()
+    return state, store
 
 
 def run_rounds(args, cfg, fed, alg, state, store, burn_stateful, start_round,

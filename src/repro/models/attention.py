@@ -178,13 +178,15 @@ def cache_from_prefill(cfg: ModelConfig, spec: LayerSpec, kv, max_len: int,
 
 
 def attn_decode(p, x, cache: AttnCache, cfg: ModelConfig, spec: LayerSpec,
-                pos, use_pallas: bool = False):
+                pos, use_pallas: bool = False, pallas_interpret: bool = False):
     """One-token decode. x: (B, 1, d); pos: traced scalar = index of the new
     token. Returns (y, new_cache).
 
     ``use_pallas=True`` routes the attention itself through the fused
     ``kernels.swa_decode`` Pallas kernel (flash-decode over the ring
     buffer); default is the pure-jnp path the kernel is validated against.
+    ``pallas_interpret=True`` runs that kernel in the Pallas interpreter
+    (CPU tests); otherwise it is compiled for the TPU.
     """
     B = x.shape[0]
     L = cache.k.shape[1]
@@ -205,6 +207,7 @@ def attn_decode(p, x, cache: AttnCache, cfg: ModelConfig, spec: LayerSpec,
         out = kernel_ops.swa_decode(
             q[:, 0], ck.astype(q.dtype), cv.astype(q.dtype), sp,
             jnp.asarray(pos, jnp.int32), window=window,
+            interpret=pallas_interpret,
         ).reshape(B, 1, cfg.q_dim)
     else:
         qpos = jnp.asarray(pos)[None]

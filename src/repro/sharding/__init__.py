@@ -6,7 +6,7 @@ from repro.sharding.rules import (  # noqa: F401
     fsdp_constrain,
     fsdp_shardings,
     logical_spec,
-    make_mesh_compat,
+    make_mesh,
     param_shardings,
     tp_constrain,
 )

@@ -33,14 +33,6 @@ from collections import defaultdict
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: 0.4.x returns a
-    one-element list of per-device dicts, newer jax returns the dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
     "s16": 2, "u16": 2, "f16": 2, "bf16": 2,

@@ -18,7 +18,7 @@ import threading
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 DEFAULT_RULES = {
     "clients": ("pod", "data"),
@@ -31,23 +31,12 @@ DEFAULT_RULES = {
 _STATE = threading.local()
 
 
-def make_mesh_compat(axis_shapes, axis_names, *, devices=None,
-                     explicit=False) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    jax >= 0.5 grew an ``axis_types`` kwarg (``jax.sharding.AxisType``);
-    0.4.x has neither the kwarg nor the enum. Tests and launch scripts call
-    this instead of ``jax.make_mesh`` so both lines work. ``explicit=True``
-    requests AxisType.Explicit axes where supported (Auto otherwise).
-    """
-    kw = {} if devices is None else {"devices": devices}
-    try:
-        from jax.sharding import AxisType  # noqa: PLC0415
-    except ImportError:  # jax 0.4.x: auto axes are the only behavior
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    kind = AxisType.Explicit if explicit else AxisType.Auto
+def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the model code
+    places arrays through sharding constraints, not explicit-axis types."""
     return jax.make_mesh(axis_shapes, axis_names,
-                         axis_types=tuple(kind for _ in axis_names), **kw)
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def current_mesh() -> Optional[Mesh]:

@@ -17,11 +17,11 @@ from repro.core.server import init_server_state
 from repro.core.sharded_round import make_fed_round
 from repro.models import init_params
 from repro.optim import get_optimizer
-from repro.sharding import (axis_rules, fsdp_shardings, make_mesh_compat,
+from repro.sharding import (axis_rules, fsdp_shardings, make_mesh,
                             param_shardings)
 
 assert jax.device_count() == 8, jax.device_count()
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 
 cfg = configs.get_smoke("fedlm-100m")
 fed = FedConfig(algorithm="fedpa", clients_per_round=4, local_steps=4,

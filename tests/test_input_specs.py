@@ -3,18 +3,18 @@ the dry-run lowers against (no device allocation; single-device mesh)."""
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro import configs
 from repro.configs.base import SHAPES
 from repro.launch.dryrun import default_fed_config
 from repro.launch.specs import input_specs
-from repro.sharding import make_mesh_compat
+from repro.sharding import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    # version-guarded: jax 0.4.x has no AxisType / axis_types kwarg
-    return make_mesh_compat((1,), ("data",))
+    return make_mesh((1,), ("data",))
 
 
 @pytest.mark.parametrize("arch", configs.ASSIGNED_ARCHS)
@@ -73,15 +73,11 @@ def test_device_store_specs_pads_non_divisible_population():
     """Regression: a population that doesn't divide the client-axis extent
     used to fall back to full replication silently; it must now pad N up
     and keep the population axis sharded."""
-    try:
-        from jax.sharding import AbstractMesh
-    except ImportError:
-        pytest.skip("jax without AbstractMesh")
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.specs import device_store_specs
 
-    mesh = AbstractMesh((("data", 8), ("model", 2)))
+    mesh = AbstractMesh((8, 2), ("data", "model"))
     cfg = configs.get_config("xlstm-125m")
     fed = default_fed_config("scaffold")
     store_spec, store_sh, ids_spec, ids_sh = device_store_specs(
@@ -105,12 +101,8 @@ def test_device_store_specs_pads_non_divisible_population():
 def test_store_population_layout_is_specs_source_of_truth():
     """launch.specs delegates population layout to core.client_state —
     one definition of padding/extent for specs, store, and dry-run."""
-    try:
-        from jax.sharding import AbstractMesh
-    except ImportError:
-        pytest.skip("jax without AbstractMesh")
     from repro.core.client_state import population_layout
     from repro.launch.specs import store_population_layout
 
-    mesh = AbstractMesh((("data", 8), ("model", 2)))
+    mesh = AbstractMesh((8, 2), ("data", "model"))
     assert store_population_layout(mesh, 10) == population_layout(mesh, 10)

@@ -22,7 +22,8 @@ def test_dp_delta_flat_vs_core_and_dense(d, ell, dtype):
     x0 = jnp.asarray(r.normal(size=d), dtype)
     xs = jnp.asarray(r.normal(size=(ell, d)), dtype)
     rho = 0.4
-    got = np.asarray(ops.dp_delta_flat(x0, xs, rho=rho))
+    got = np.asarray(ops.dp_delta_flat(x0, xs, rho=rho,
+                                               interpret=True))
     want = np.asarray(dp_delta(x0, xs, rho))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
     oracle = np.asarray(dense_delta(x0, xs, rho))
@@ -39,7 +40,8 @@ def test_dp_step_vs_ref(t):
     delta = jnp.asarray(r.normal(size=d), jnp.float32)
     V = jnp.asarray(r.normal(size=(lp, d)), jnp.float32)
     c_hist = jnp.asarray(np.abs(r.normal(size=lp)), jnp.float32)
-    v_k, d_k, a_k, c_k = ops.dp_step(u, delta, V, c_hist, t, rho=rho)
+    v_k, d_k, a_k, c_k = ops.dp_step(u, delta, V, c_hist, t, rho=rho,
+                                     interpret=True)
     v_r, d_r, a_r, c_r = ref.dp_step_ref(u, delta, V, c_hist, t, rho)
     np.testing.assert_allclose(np.asarray(v_k), np.asarray(v_r), rtol=1e-5,
                                atol=1e-5)
@@ -56,7 +58,7 @@ def test_dp_reduce_partials_vs_ref():
     u = jnp.asarray(r.normal(size=d), jnp.float32)
     delta = jnp.asarray(r.normal(size=d), jnp.float32)
     V = jnp.asarray(r.normal(size=(lp, d)), jnp.float32)
-    dots, uu, ud = dp_reduce(u, delta, V)
+    dots, uu, ud = dp_reduce(u, delta, V, interpret=True)
     dots_r, uu_r, ud_r = ref.dp_reduce_ref(u, delta, V)
     np.testing.assert_allclose(np.asarray(dots), np.asarray(dots_r),
                                rtol=1e-5)
@@ -82,7 +84,8 @@ def test_swa_decode_sweep(B, H, KV, dh, L, window, dtype):
     v = jnp.asarray(r.normal(size=(B, L, KV, dh)), dtype)
     pos = L - 50
     slot = jnp.where(jnp.arange(L) <= pos, jnp.arange(L), -1).astype(jnp.int32)
-    got = ops.swa_decode(q, k, v, slot, pos, window=window)
+    got = ops.swa_decode(q, k, v, slot, pos, window=window,
+                         interpret=True)
     want = ref.swa_decode_ref(q.reshape(B, KV, H // KV, dh), k, v, slot, pos,
                               window=window).reshape(B, H, dh)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -104,7 +107,7 @@ def test_swa_decode_ring_buffer_layout():
     slot_pos = np.where(slots <= pos % L, (pos // L) * L + slots,
                         (pos // L - 1) * L + slots).astype(np.int32)
     sp = jnp.asarray(slot_pos)
-    got = ops.swa_decode(q, k, v, sp, pos, window=W)
+    got = ops.swa_decode(q, k, v, sp, pos, window=W, interpret=True)
     want = ref.swa_decode_ref(q.reshape(B, KV, H, dh), k, v, sp, pos,
                               window=W).reshape(B, H, dh)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.sharding.hlo_cost import analyze, xla_cost_analysis
+from repro.sharding.hlo_cost import analyze
 
 D = 128
 UNIT = 2 * D**3  # one (D,D)@(D,D) matmul
@@ -12,7 +12,7 @@ UNIT = 2 * D**3  # one (D,D)@(D,D) matmul
 
 def _flops(fn, *args):
     comp = jax.jit(fn).lower(*args).compile()
-    return analyze(comp.as_text())["flops"], xla_cost_analysis(comp)["flops"]
+    return analyze(comp.as_text())["flops"], comp.cost_analysis()["flops"]
 
 
 def _xw():
