@@ -6,7 +6,10 @@ calls it at import time, so test processes stay uncached. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no other path
 is set here. Otherwise the cache lives at the fixed ``<checkout>/.jax_cache``:
 the directory is part of the cache key, so a path built from a temp name, a
-pid or the time would never hit.
+pid or the time would never hit. ``<checkout>`` is found from this file's
+place in ``src/repro/launch``, so the default assumes the package runs from
+a source tree or an editable install; a non-editable install should set
+``JAX_COMPILATION_CACHE_DIR``.
 """
 from __future__ import annotations
 

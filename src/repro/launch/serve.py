@@ -3,7 +3,7 @@ decode tokens with the cached state — the decode_32k/long_500k code path at
 CPU-friendly scale.
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-27b --smoke \
-      --batch 4 --prompt-len 96 --gen 32 [--pallas [--pallas-interpret]]
+      --batch 4 --prompt-len 96 --gen 32 [--pallas[=interpret]]
 """
 from __future__ import annotations
 
@@ -31,12 +31,12 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--sample", action="store_true")
     ap.add_argument("--temperature", type=float, default=1.0)
-    ap.add_argument("--pallas", action="store_true",
+    ap.add_argument("--pallas", nargs="?", const="compiled", default=None,
+                    choices=("compiled", "interpret"),
                     help="route decode attention through the Pallas "
-                         "swa_decode kernel, compiled for the TPU")
-    ap.add_argument("--pallas-interpret", action="store_true",
-                    help="run that kernel in the Pallas interpreter instead "
-                         "(a CPU host has no compiled Pallas)")
+                         "swa_decode kernel, compiled for the TPU; "
+                         "--pallas=interpret runs it in the Pallas "
+                         "interpreter (a CPU host has no compiled Pallas)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     enable_compile_cache()
@@ -71,8 +71,7 @@ def main():
 
     step = jax.jit(lambda p, t, s, r: serve_step(
         p, t, s, cfg, sample=args.sample, rng=r,
-        temperature=args.temperature, use_pallas=args.pallas,
-        pallas_interpret=args.pallas_interpret))
+        temperature=args.temperature, pallas=args.pallas))
     out = [np.asarray(tok)]
     rng = jax.random.PRNGKey(args.seed + 1)
     t1 = time.time()

@@ -108,6 +108,10 @@ def parse_args(argv=None):
     ap.add_argument("--client-lr", type=float, default=0.05)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq-len", type=int, default=128)
+    ap.add_argument("--compute-dtype", default="bfloat16",
+                    choices=("bfloat16", "float32"),
+                    help="dtype of the client steps' and the eval's "
+                         "activations (params are kept in float32)")
     ap.add_argument("--payload-codec", default="none",
                     help="client payload codec chain (repro.compression): "
                          "none | lowrank | int8 | lowrank+int8; non-'none' "
@@ -227,6 +231,8 @@ def make_eval_fn(args, cfg, data, s_text, q_chunk):
                                      cfg.frontend_tokens, cfg.d_model),
             jnp.bfloat16)
     return jax.jit(lambda p: lm_loss(p, eval_batch, cfg,
+                                     compute_dtype=jnp.dtype(
+                                         args.compute_dtype),
                                      q_chunk=q_chunk)[0])
 
 
@@ -398,25 +404,24 @@ def run_rounds(args, cfg, fed, alg, state, store, burn_stateful, start_round,
         # after round one the server state is a round output and stays so
         state = replicate_global(state, pop_mesh)
     has_burn = alg.has_burn_regime and fed.burn_in_rounds > 0
-    cohort_fn, server_fn = make_fed_round_split(
-        cfg, fed, placement="parallel", q_chunk=q_chunk)
+    program = dict(placement="parallel", q_chunk=q_chunk,
+                   compute_dtype=jnp.dtype(args.compute_dtype))
+    cohort_fn, server_fn = make_fed_round_split(cfg, fed, **program)
     burn_cohort_fn = burn_server_fn = None
     if has_burn:
         burn_cohort_fn, burn_server_fn = make_fed_round_split(
-            cfg, fed, placement="parallel", q_chunk=q_chunk,
-            use_sampling=False)
+            cfg, fed, use_sampling=False, **program)
     rb = round_bytes(fed, state.params)
     burn_rb = (round_bytes(fed, state.params, use_sampling=False)
                if has_burn else rb)
     engine = RoundEngine(
         cohort_fn=cohort_fn,
         server_fn=server_fn,
-        round_fn=make_fed_round(cfg, fed, placement="parallel",
-                                q_chunk=q_chunk),
+        round_fn=make_fed_round(cfg, fed, **program),
         burn_cohort_fn=burn_cohort_fn,
         burn_server_fn=burn_server_fn,
-        burn_round_fn=(make_fed_round(cfg, fed, placement="parallel",
-                                      q_chunk=q_chunk, use_sampling=False)
+        burn_round_fn=(make_fed_round(cfg, fed, use_sampling=False,
+                                      **program)
                        if has_burn else None),
         burn_in_rounds=max(0, fed.burn_in_rounds - start_round),
         max_staleness=fed.max_staleness if fed.async_rounds else 0,

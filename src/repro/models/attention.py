@@ -12,7 +12,7 @@ mask rule covers both.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -178,16 +178,19 @@ def cache_from_prefill(cfg: ModelConfig, spec: LayerSpec, kv, max_len: int,
 
 
 def attn_decode(p, x, cache: AttnCache, cfg: ModelConfig, spec: LayerSpec,
-                pos, use_pallas: bool = False, pallas_interpret: bool = False):
+                pos, pallas: Optional[str] = None):
     """One-token decode. x: (B, 1, d); pos: traced scalar = index of the new
     token. Returns (y, new_cache).
 
-    ``use_pallas=True`` routes the attention itself through the fused
+    ``pallas="compiled"`` routes the attention itself through the fused
     ``kernels.swa_decode`` Pallas kernel (flash-decode over the ring
-    buffer); default is the pure-jnp path the kernel is validated against.
-    ``pallas_interpret=True`` runs that kernel in the Pallas interpreter
-    (CPU tests); otherwise it is compiled for the TPU.
+    buffer), compiled for the TPU; ``pallas="interpret"`` runs that kernel
+    in the Pallas interpreter (CPU). The default ``None`` is the pure-jnp
+    path the kernel is validated against.
     """
+    if pallas not in (None, "compiled", "interpret"):
+        raise ValueError(f"pallas={pallas!r}: expected None, 'compiled' "
+                         "or 'interpret'")
     B = x.shape[0]
     L = cache.k.shape[1]
     window = spec.window if spec.mixer == "swa" else 0
@@ -202,12 +205,12 @@ def attn_decode(p, x, cache: AttnCache, cfg: ModelConfig, spec: LayerSpec,
     sp = jax.lax.dynamic_update_index_in_dim(cache.slot_pos,
                                              pos.astype(jnp.int32), slot, axis=0)
 
-    if use_pallas:
+    if pallas:
         from repro.kernels import ops as kernel_ops  # lazy: pallas import
         out = kernel_ops.swa_decode(
             q[:, 0], ck.astype(q.dtype), cv.astype(q.dtype), sp,
             jnp.asarray(pos, jnp.int32), window=window,
-            interpret=pallas_interpret,
+            interpret=pallas == "interpret",
         ).reshape(B, 1, cfg.q_dim)
     else:
         qpos = jnp.asarray(pos)[None]

@@ -273,12 +273,11 @@ def abstract_decode_state(cfg: ModelConfig, batch: int, max_len: int,
 
 
 def _decode_layer(p, x, cache, cfg: ModelConfig, spec: LayerSpec, pos,
-                  use_pallas: bool = False, pallas_interpret: bool = False):
+                  pallas: Optional[str] = None):
     h = rms_norm(x, p["norm"], cfg.norm_eps)
     if spec.mixer in ("attn", "swa"):
         y, cache = attn.attn_decode(p["mixer"], h, cache, cfg, spec, pos,
-                                    use_pallas=use_pallas,
-                                    pallas_interpret=pallas_interpret)
+                                    pallas=pallas)
     elif spec.mixer == "mlstm":
         y, cache = xlstm_mod.mlstm_decode(p["mixer"], h, cache, cfg)
     elif spec.mixer == "slstm":
@@ -301,10 +300,9 @@ def _decode_layer(p, x, cache, cfg: ModelConfig, spec: LayerSpec, pos,
 
 
 def decode_step(params, token, state: DecodeState, cfg: ModelConfig, *,
-                compute_dtype=jnp.bfloat16, use_pallas: bool = False,
-                pallas_interpret: bool = False):
+                compute_dtype=jnp.bfloat16, pallas: Optional[str] = None):
     """One token for the whole batch. token: (B,) int32. Returns
-    (logits (B, V), new_state)."""
+    (logits (B, V), new_state). ``pallas``: see ``attention.attn_decode``."""
     cparams = jax.tree_util.tree_map(
         lambda t: t.astype(compute_dtype)
         if jnp.issubdtype(t.dtype, jnp.floating) else t,
@@ -319,8 +317,7 @@ def decode_step(params, token, state: DecodeState, cfg: ModelConfig, *,
         for i, spec in enumerate(cfg.pattern):
             x, c = _decode_layer(unit_params[f"pos_{i}"], x,
                                  unit_cache[f"pos_{i}"], cfg, spec, pos,
-                                 use_pallas=use_pallas,
-                                 pallas_interpret=pallas_interpret)
+                                 pallas=pallas)
             new_caches[f"pos_{i}"] = c
         return x, new_caches
 
@@ -329,8 +326,7 @@ def decode_step(params, token, state: DecodeState, cfg: ModelConfig, *,
     for i, spec in enumerate(cfg.tail):
         x, c = _decode_layer(cparams["tail"][f"layer_{i}"], x,
                              state.tail[f"layer_{i}"], cfg, spec, pos,
-                             use_pallas=use_pallas,
-                             pallas_interpret=pallas_interpret)
+                             pallas=pallas)
         new_tail[f"layer_{i}"] = c
     x = rms_norm(x, cparams["final_norm"], cfg.norm_eps)
     logits = _unembed(cparams, x)[:, 0]

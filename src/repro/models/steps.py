@@ -58,13 +58,13 @@ def centralized_train_step(params, opt_state, batch, cfg: ModelConfig, opt,
 def serve_step(params, token, state, cfg: ModelConfig, *,
                compute_dtype=jnp.bfloat16, sample: bool = False,
                rng: Optional[jax.Array] = None, temperature: float = 1.0,
-               use_pallas: bool = False, pallas_interpret: bool = False):
+               pallas: Optional[str] = None):
     """One decode step for a batch of requests. token: (B,) int32.
-    Returns (next_token (B,), logits (B, V), new_state)."""
+    Returns (next_token (B,), logits (B, V), new_state). ``pallas``: see
+    ``attention.attn_decode``."""
     logits, state = model_lib.decode_step(params, token, state, cfg,
                                           compute_dtype=compute_dtype,
-                                          use_pallas=use_pallas,
-                                          pallas_interpret=pallas_interpret)
+                                          pallas=pallas)
     # padded vocab rows must never be sampled
     pad_mask = jnp.arange(logits.shape[-1]) >= cfg.vocab_size
     logits = jnp.where(pad_mask, -1e30, logits.astype(jnp.float32))

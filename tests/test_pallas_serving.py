@@ -1,4 +1,4 @@
-"""The Pallas-kernel serving path (use_pallas=True) must produce the same
+"""The Pallas-kernel serving path (``pallas=...``) must produce the same
 logits as the pure-jnp decode path it is validated against."""
 import jax
 import jax.numpy as jnp
@@ -24,6 +24,6 @@ def test_pallas_decode_matches_jnp(arch):
                                    compute_dtype=jnp.float32)
         l_pal, s_pal = decode_step(params, toks[:, t], s_pal, cfg,
                                    compute_dtype=jnp.float32,
-                                   use_pallas=True, pallas_interpret=True)
+                                   pallas="interpret")
         np.testing.assert_allclose(np.asarray(l_pal), np.asarray(l_ref),
                                    rtol=2e-3, atol=2e-3)

@@ -5,6 +5,9 @@ runs, so a kernel or a program the chip's compiler refuses fails here.
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and xdist workers import every
 test file."""
+import contextlib
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -29,6 +32,8 @@ HBM_LIMIT = 16_909_336_064
 def one_chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
+    # the TPU library loaded below would otherwise log under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -80,19 +85,24 @@ def test_swa_decode_compiles_at_gemma3_window(one_chip):
 
 #: chip_smoke.py's cohort: 5 clients x 8 local steps x batch 4 x seq 128
 C, K, B, S = 5, 8, 4, 128
+_SCAFFOLD = dict(algorithm="scaffold", client_opt="sgd",
+                 client_state_placement="device")
+#: name -> (clients, compute dtype, FedConfig fields)
 ROUNDS = {
-    "fedpa": dict(algorithm="fedpa", burn_in_steps=4, steps_per_sample=2,
-                  client_opt="sgdm"),
-    "scaffold_device_store": dict(algorithm="scaffold", client_opt="sgd",
-                                  client_state_placement="device"),
+    "fedpa": (C, jnp.bfloat16, dict(algorithm="fedpa", burn_in_steps=4,
+                                    steps_per_sample=2, client_opt="sgdm")),
+    "scaffold_device_store": (C, jnp.bfloat16, _SCAFFOLD),
+    # the one-chip side of ``chip_smoke.py --chips 4``'s fp32 comparison
+    "scaffold_device_store_fp32": (4, jnp.float32, _SCAFFOLD),
 }
 
 
 @pytest.mark.parametrize("name", list(ROUNDS))
 def test_fedlm_round_fits_one_chip(one_chip, name):
+    C, dtype, fields = ROUNDS[name]
     cfg = configs.get_config("fedlm-100m")
     fed = FedConfig(clients_per_round=C, local_steps=K, server_opt="sgdm",
-                    server_lr=0.5, client_lr=0.05, **ROUNDS[name])
+                    server_lr=0.5, client_lr=0.05, **fields)
     alg = get_algorithm(fed)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == D_FEDLM
@@ -102,21 +112,26 @@ def test_fedlm_round_fits_one_chip(one_chip, name):
         jax.eval_shape(lambda p: init_server_state(p, opt, algorithm=alg),
                        params))
     batches = {"tokens": _spec(one_chip, (C, K, B, S + 1), jnp.int32)}
-    round_fn = make_fed_round(cfg, fed, placement="parallel", q_chunk=64)
-    if alg.stateful:
-        N = 8
-        store = {
-            "buffers": jax.tree_util.tree_map(
-                lambda x: _spec(one_chip, (N,) + x.shape, x.dtype),
-                jax.eval_shape(alg.init_client_state, params)),
-            "stamps": _spec(one_chip, (N,), jnp.int32),
-        }
-        lowered = jax.jit(round_fn, donate_argnums=(3,)).lower(
-            state, batches, None, store, _spec(one_chip, (C,), jnp.int32),
-            None)
-    else:
-        lowered = jax.jit(round_fn).lower(state, batches, None, None)
-    mem = lowered.compile().memory_analysis()
+    round_fn = make_fed_round(cfg, fed, placement="parallel", q_chunk=64,
+                              compute_dtype=dtype)
+    # chip_smoke runs its fp32 rounds with full-precision matmuls
+    precision = (jax.default_matmul_precision("highest")
+                 if dtype == jnp.float32 else contextlib.nullcontext())
+    with precision:
+        if alg.stateful:
+            N = 8
+            store = {
+                "buffers": jax.tree_util.tree_map(
+                    lambda x: _spec(one_chip, (N,) + x.shape, x.dtype),
+                    jax.eval_shape(alg.init_client_state, params)),
+                "stamps": _spec(one_chip, (N,), jnp.int32),
+            }
+            lowered = jax.jit(round_fn, donate_argnums=(3,)).lower(
+                state, batches, None, store,
+                _spec(one_chip, (C,), jnp.int32), None)
+        else:
+            lowered = jax.jit(round_fn).lower(state, batches, None, None)
+        mem = lowered.compile().memory_analysis()
     if alg.stateful:
         # the donated store is updated in place
         assert mem.alias_size_in_bytes >= 8 * 4 * D_FEDLM
