@@ -18,7 +18,7 @@ from repro.algorithms.base import (ClientResult, FedAlgorithm,
 from repro.core import tree_math as tm
 from repro.core.dp_delta import (dp_delta, online_dp_delta, online_dp_init,
                                  online_dp_update)
-from repro.core.iasg import iasg_sample, sgd_steps
+from repro.core.iasg import iasg_sample, sample_window, sgd_steps
 from repro.optim import Optimizer
 
 
@@ -145,24 +145,11 @@ class FedPA(FedAlgorithm):
 
             def window(carry, wb):
                 p, s, dp = carry
-
-                def step(inner, batch):
-                    p, s, acc = inner
-                    loss, grads = grad_fn(p, batch)
-                    upd, s = client_opt.update(grads, s, p)
-                    p = tm.tmap(lambda pi, u: pi + u.astype(pi.dtype), p, upd)
-                    acc = tm.tmap(lambda a, pi: a + pi.astype(delta_dtype),
-                                  acc, p)
-                    return (p, s, acc), loss
-
-                # The IASG sample space IS delta_dtype by contract: this
-                # matches iasg.py's batch path bit-for-bit, and the fp32
-                # accumulation happens downstream in the Sherman-Morrison
-                # online-DP state, not in this window average.
-                # fedlint: disable=FL003 -- IASG samples live in delta_dtype by contract
-                acc0 = tm.tzeros_like(p, delta_dtype)
-                (p, s, acc), losses = jax.lax.scan(step, (p, s, acc0), wb)
-                sample = tm.tscale(1.0 / K_s, acc)
+                # The IASG sample space IS delta_dtype by contract, as in
+                # the batch path; the fp32 accumulation happens downstream
+                # in the Sherman-Morrison online-DP state.
+                p, s, sample, losses = sample_window(p, s, client_opt,
+                                                     grad_fn, wb, delta_dtype)
                 dp = online_dp_update(dp, sample, rho)
                 return (p, s, dp), losses
 

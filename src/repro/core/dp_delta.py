@@ -64,12 +64,13 @@ def dp_delta(x0, samples, rho, return_mean=False):
     # DP in >= fp32 (bf16 deltas are re-cast by the caller, see client.py)
     dtype = jnp.promote_types(
         jax.tree_util.tree_leaves(samples)[0].dtype, jnp.float32)
-    state0 = online_dp_init(x0, ell, dtype=dtype)
 
     def body(state, x_t):
         return online_dp_update(state, x_t, rho), None
 
-    state, _ = jax.lax.scan(body, state0, tm.tcast(samples, dtype))
+    with jax.named_scope("dp_delta"):
+        state0 = online_dp_init(x0, ell, dtype=dtype)
+        state, _ = jax.lax.scan(body, state0, tm.tcast(samples, dtype))
     delta = online_dp_delta(state, rho)
     if return_mean:
         return delta, state.xbar
@@ -147,7 +148,8 @@ def online_dp_update(state: DPState, x_t, rho) -> DPState:
             c_hist=c_hist,
         )
 
-    return jax.lax.cond(state.t == 0, first, rest, state)
+    with jax.named_scope("dp_delta"):
+        return jax.lax.cond(state.t == 0, first, rest, state)
 
 
 def online_dp_delta(state: DPState, rho):
@@ -156,9 +158,10 @@ def online_dp_delta(state: DPState, rho):
     With t=0 this returns zeros; with t=1 it returns x0 - x1 == the FedAvg
     delta (the paper's any-time property).
     """
-    tf = jnp.maximum(state.t, 1).astype(state.c_hist.dtype)
-    r = 1.0 / (1.0 + (tf - 1.0) * rho)
-    return tm.tscale(1.0 / r, state.delta_tilde)
+    with jax.named_scope("dp_delta"):
+        tf = jnp.maximum(state.t, 1).astype(state.c_hist.dtype)
+        r = 1.0 / (1.0 + (tf - 1.0) * rho)
+        return tm.tscale(1.0 / r, state.delta_tilde)
 
 
 def _hist_dots(v_hist, u):

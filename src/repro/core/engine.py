@@ -44,6 +44,7 @@ from repro.core.client_state import (ClientStateStore, DeviceClientStateStore,
                                      device_scatter, jit_donating_store)
 from repro.core.history import RoundRecorder
 from repro.core.server import ServerState
+from repro.core.spans import span
 from repro.data.prefetch import Cohort, close_prefetcher, make_prefetcher
 
 #: build_cohort(round_idx) -> Cohort (see data/prefetch.py)
@@ -370,6 +371,11 @@ class RoundEngine:
         raw (possibly still-on-device) metrics and the post-update state —
         for live logging/checkpointing. Forcing metrics there re-introduces
         a per-round sync, so log sparingly in throughput-sensitive loops.
+
+        Each round is host span ``repro.round`` (``core.spans``), holding
+        ``repro.cohort_get`` (prefetch wait or inline build), ``repro.dispatch``
+        (the host's enqueue of a round's programs: once per round fused,
+        twice split), ``repro.eval`` and ``repro.on_round``.
         """
         if eval_fn is not None and eval_every < 1:
             raise ValueError(
@@ -389,38 +395,47 @@ class RoundEngine:
         completed = False
         try:
             for t_apply in range(num_rounds):
-                # keep up to max_staleness cohorts in flight beyond the one
-                # being applied; each remembers the params version it saw.
-                # The fused backend (window=1) has nothing in flight — its
-                # "dispatch" is just the host-side cohort build.
-                while (t_next < num_rounds
-                       and len(pending) <= self.max_staleness):
-                    cohort = get(t_next)
-                    pending.append(cohort if fused else
-                                   self._dispatch(state, cohort, t_next,
-                                                  version))
-                    t_next += 1
+                with span("round"):
+                    # keep up to max_staleness cohorts in flight beyond the
+                    # one being applied; each remembers the params version
+                    # it saw. The fused backend (window=1) has nothing in
+                    # flight — its "dispatch" is the fused round below.
+                    while (t_next < num_rounds
+                           and len(pending) <= self.max_staleness):
+                        with span("cohort_get"):
+                            cohort = get(t_next)
+                        if fused:
+                            pending.append(cohort)
+                        else:
+                            with span("dispatch"):
+                                pending.append(self._dispatch(
+                                    state, cohort, t_next, version))
+                        t_next += 1
 
-                item = pending.popleft()
-                if fused:
-                    out = self._apply_fused(state, item, t_apply)
-                else:
-                    assert item.round_idx == t_apply, (item.round_idx,
-                                                       t_apply)
-                    out = self._apply_pipelined(state, item, version)
-                state = out.state
-                version += 1
-                ev = (eval_fn(state.params)
-                      if eval_fn is not None and (t_apply % eval_every == 0
-                                                  or t_apply == num_rounds - 1)
-                      else None)
-                rec = recorder.record(
-                    round_idx=t_apply, metrics=out.metrics,
-                    is_burn=out.is_burn, staleness=out.staleness,
-                    dropped=out.dropped, straggled=out.straggled,
-                    state_drops=out.state_drops, eval_metrics=ev)
-                if on_round is not None:
-                    on_round(rec, state)
+                    item = pending.popleft()
+                    with span("dispatch"):
+                        if fused:
+                            out = self._apply_fused(state, item, t_apply)
+                        else:
+                            assert item.round_idx == t_apply, (
+                                item.round_idx, t_apply)
+                            out = self._apply_pipelined(state, item,
+                                                        version)
+                    state = out.state
+                    version += 1
+                    ev = None
+                    if eval_fn is not None and (t_apply % eval_every == 0
+                                                or t_apply == num_rounds - 1):
+                        with span("eval"):
+                            ev = eval_fn(state.params)
+                    rec = recorder.record(
+                        round_idx=t_apply, metrics=out.metrics,
+                        is_burn=out.is_burn, staleness=out.staleness,
+                        dropped=out.dropped, straggled=out.straggled,
+                        state_drops=out.state_drops, eval_metrics=ev)
+                    if on_round is not None:
+                        with span("on_round"):
+                            on_round(rec, state)
             completed = True
         finally:
             if source is not None:

@@ -32,18 +32,46 @@ class IASGResult(NamedTuple):
     sample_losses: jnp.ndarray   # (num_samples, steps_per_sample)
 
 
+def _opt_step(p, opt: Optimizer, s, grads):
+    """One client-optimizer step: ``(params, opt_state)`` after ``grads``."""
+    with jax.named_scope("client_opt"):
+        updates, s = opt.update(grads, s, p)
+        p = tm.tmap(lambda pi, u: pi + u.astype(pi.dtype), p, updates)
+    return p, s
+
+
 def sgd_steps(params, opt: Optimizer, opt_state, grad_fn: GradFn, batches):
     """Plain local SGD over the leading axis of ``batches`` (FedAvg client)."""
 
     def body(carry, batch):
         p, s = carry
         loss, grads = grad_fn(p, batch)
-        updates, s = opt.update(grads, s, p)
-        p = tm.tmap(lambda pi, u: pi + u.astype(pi.dtype), p, updates)
-        return (p, s), loss
+        return _opt_step(p, opt, s, grads), loss
 
     (params, opt_state), losses = jax.lax.scan(body, (params, opt_state), batches)
     return params, opt_state, losses
+
+
+def sample_window(p, s, opt: Optimizer, grad_fn: GradFn, window_batches,
+                  sample_dtype=jnp.float32):
+    """One IASG window: SGD over the leading axis of ``window_batches``;
+    the sample is the Polyak average of the window's iterates, in
+    ``sample_dtype``. Returns ``(params, opt_state, sample, losses)``."""
+
+    def step(inner, batch):
+        p, s, acc = inner
+        loss, grads = grad_fn(p, batch)
+        p, s = _opt_step(p, opt, s, grads)
+        with jax.named_scope("iasg_average"):
+            acc = tm.tmap(lambda a, pi: a + pi.astype(sample_dtype), acc, p)
+        return (p, s, acc), loss
+
+    acc0 = tm.tzeros_like(p, sample_dtype)
+    (p, s, acc), losses = jax.lax.scan(step, (p, s, acc0), window_batches)
+    steps = jax.tree_util.tree_leaves(window_batches)[0].shape[0]
+    with jax.named_scope("iasg_average"):
+        sample = tm.tscale(1.0 / steps, acc)
+    return p, s, sample, losses
 
 
 def iasg_sample(
@@ -82,19 +110,8 @@ def iasg_sample(
     )
 
     def window(carry, window_batches):
-        p, s = carry
-
-        def step(inner, batch):
-            p, s, acc = inner
-            loss, grads = grad_fn(p, batch)
-            updates, s = opt.update(grads, s, p)
-            p = tm.tmap(lambda pi, u: pi + u.astype(pi.dtype), p, updates)
-            acc = tm.tmap(lambda a, pi: a + pi.astype(sample_dtype), acc, p)
-            return (p, s, acc), loss
-
-        acc0 = tm.tzeros_like(p, sample_dtype)
-        (p, s, acc), losses = jax.lax.scan(step, (p, s, acc0), window_batches)
-        sample = tm.tscale(1.0 / steps_per_sample, acc)
+        p, s, sample, losses = sample_window(*carry, opt, grad_fn,
+                                             window_batches, sample_dtype)
         return (p, s), (sample, losses)
 
     (params, opt_state), (samples, sample_losses) = jax.lax.scan(

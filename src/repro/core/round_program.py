@@ -149,8 +149,9 @@ def _run_parallel(ctx, params, client_batches, weights, extras, cstates):
     res = vm(params, client_batches,
              *((cstates,) if ctx.stateful else ()), *extras)
     w = _qffl_weights(ctx, weights, res.metrics)
-    return (ctx.alg.reduce_stacked(res.payload, w), res.metrics,
-            res.state_update)
+    with jax.named_scope("aggregate"):
+        agg = ctx.alg.reduce_stacked(res.payload, w)
+    return agg, res.metrics, res.state_update
 
 
 def _zero_accum(ctx, params):
@@ -166,9 +167,10 @@ def _run_sequential(ctx, params, client_batches, weights, extras, cstates):
         batches, w, cs = xs
         res = ctx.client_update(params, batches,
                                 *((cs,) if ctx.stateful else ()), *extras)
-        return (ctx.alg.accumulate(acc, res.payload,
-                                   _qffl_weights(ctx, w, res.metrics)),
-                (res.metrics, res.state_update))
+        w = _qffl_weights(ctx, w, res.metrics)
+        with jax.named_scope("aggregate"):
+            acc = ctx.alg.accumulate(acc, res.payload, w)
+        return acc, (res.metrics, res.state_update)
 
     agg, (metrics, new_states) = jax.lax.scan(
         body, _zero_accum(ctx, params),
@@ -207,9 +209,10 @@ def _run_chunked(ctx, params, client_batches, weights, extras, cstates,
                       spmd_axis_name=ctx.spmd_axes)
         res = vm(params, batches,
                  *((cs,) if ctx.stateful else ()), *extras)
-        acc = tm.tmap(lambda a, c: a + c.astype(a.dtype),
-                      acc, ctx.alg.reduce_stacked(
-                          res.payload, _qffl_weights(ctx, w, res.metrics)))
+        w = _qffl_weights(ctx, w, res.metrics)
+        with jax.named_scope("aggregate"):
+            acc = tm.tmap(lambda a, c: a + c.astype(a.dtype),
+                          acc, ctx.alg.reduce_stacked(res.payload, w))
         return acc, (res.metrics, res.state_update)
 
     agg, (metrics, new_states) = jax.lax.scan(
@@ -267,7 +270,8 @@ def _run_cohort(ctx: _CohortCtx, state: ServerState, client_batches,
     # the cohort program: fedlora decodes its low-rank accumulator here with
     # the dispatch-time state.round (the async engine may apply the result
     # against a newer server state)
-    agg = ctx.alg.finish_cohort(state, agg)
+    with jax.named_scope("aggregate"):
+        agg = ctx.alg.finish_cohort(state, agg)
 
     if survivor_mask is None:
         losses = {
@@ -439,13 +443,14 @@ def make_server_program(
                                              fed.server_momentum)
 
     def server_fn(state: ServerState, agg, discount=None):
-        params = (state.params if prepare_params is None
-                  else prepare_params(state.params))
-        new_state = alg.server_update(state._replace(params=params), agg,
-                                      server_opt, discount)
-        if finalize_params is not None:
-            new_state = new_state._replace(
-                params=finalize_params(new_state.params))
+        with jax.named_scope("server_update"):
+            params = (state.params if prepare_params is None
+                      else prepare_params(state.params))
+            new_state = alg.server_update(state._replace(params=params), agg,
+                                          server_opt, discount)
+            if finalize_params is not None:
+                new_state = new_state._replace(
+                    params=finalize_params(new_state.params))
         return new_state
 
     return server_fn

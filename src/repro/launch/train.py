@@ -39,6 +39,7 @@ from repro.core.client_state import make_client_store
 from repro.core.engine import RoundEngine
 from repro.core.server import init_server_state
 from repro.core.sharded_round import make_fed_round, make_fed_round_split
+from repro.core.spans import span
 from repro.data import SyntheticLMData
 from repro.data.cohort_source import CohortSource
 from repro.data.prefetch import (globalize_cohort_batches, local_row_range,
@@ -468,10 +469,14 @@ def run_rounds(args, cfg, fed, alg, state, store, burn_stateful, start_round,
         last_t = time.time()
         maybe_checkpoint(round_state, r)
 
+    def evaluate(params):
+        loss = eval_fn(params)
+        with span("sync"):   # the host waits for the device here
+            return {"eval_loss": float(loss)}
+
     state, _ = engine.run(
         state, build_cohort, args.rounds - start_round,
-        eval_fn=lambda p: {"eval_loss": float(eval_fn(p))},
-        on_round=on_round)
+        eval_fn=evaluate, on_round=on_round)
     return state
 
 

@@ -32,11 +32,18 @@ def lm_loss(params, batch, cfg: ModelConfig, *, compute_dtype=jnp.bfloat16,
 
 
 def lm_grad_fn(cfg: ModelConfig, **kw):
-    """The (loss, grads) client gradient function FedAvg/FedPA scan over."""
+    """The (loss, grads) client gradient function FedAvg/FedPA scan over.
+
+    The loss is traced under ``jax.named_scope("client_grad")``, inside the
+    differentiated function, so the backward pass's ops carry the scope
+    under ``transpose(...)`` and the forward's without it."""
+    def loss_fn(params, batch):
+        with jax.named_scope("client_grad"):
+            return lm_loss(params, batch, cfg, **kw)
+
     def fn(params, batch):
-        (loss, _), grads = jax.value_and_grad(
-            functools.partial(lm_loss, cfg=cfg, **kw), has_aux=True
-        )(params, batch)
+        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params, batch)
         return loss, grads
     return fn
 
