@@ -105,7 +105,7 @@ class FedPA(FedAlgorithm):
         return run
 
     def _make_batch_update(self, grad_fn, client_opt):
-        """Samples stacked first, then one ``lax.scan`` of the online DP."""
+        """Samples stacked first, then the batch DP (``dp_delta``)."""
         run = self._iasg_delta(grad_fn, client_opt)
 
         def update(params, batches):

@@ -1,4 +1,5 @@
-"""Theorem 3: the O(l^2 d) DP computes Sigma_hat^{-1}(x0 - xbar) exactly."""
+"""Theorem 3: the DP computes Sigma_hat^{-1}(x0 - xbar) exactly, in the
+Gram form (l <= d) and the full-width form (l > d) alike."""
 import sys
 
 import jax
@@ -124,6 +125,59 @@ def test_delta_converges_to_exact_with_gaussian_samples():
         errs.append(np.linalg.norm(got - exact) / np.linalg.norm(exact))
     assert errs[2] < errs[0], errs
     assert errs[2] < 0.2, errs
+
+
+@pytest.mark.parametrize("ell", [2, 4, 8])
+def test_gram_form_float32_with_common_offset(ell):
+    """f32 samples that share a large offset (100 + 0.01 N(0, 1), as
+    parameters do next to their spread): the Gram form works on
+    differences from x_1 and keeps f32 precision against the f64 oracle."""
+    r = np.random.default_rng(ell)
+    d = 1000
+    x0 = (100.0 + 0.01 * r.normal(size=d)).astype(np.float32)
+    xs = (100.0 + 0.01 * r.normal(size=(ell, d))).astype(np.float32)
+    tree0 = {"w": jnp.asarray(x0[:800].reshape(20, 40)),
+             "b": jnp.asarray(x0[800:])}
+    trees = {"w": jnp.asarray(xs[:, :800].reshape(ell, 20, 40)),
+             "b": jnp.asarray(xs[:, 800:])}
+    got = dp.dp_delta(tree0, trees, 0.5)
+    assert got["w"].dtype == jnp.float32
+    got = np.concatenate([np.asarray(got["w"]).ravel(),
+                          np.asarray(got["b"])])
+    want = np.asarray(dense_delta(jnp.asarray(x0, jnp.float64),
+                                  jnp.asarray(xs, jnp.float64), 0.5))
+    np.testing.assert_allclose(got, want, rtol=1e-5,
+                               atol=1e-5 * np.abs(want).max())
+
+
+def _loops(jaxpr):
+    """Every cond, while and scan equation in ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("cond", "while", "scan"):
+            yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _loops(inner)
+
+
+def test_gram_form_carries_no_full_width_tree():
+    """The batch DP's loops run on l x l scalars: no cond, while or scan
+    carries a parameter-sized array (the full-width form carries four)."""
+    ell = 4
+    tree0 = {"w": jnp.zeros((64, 64), jnp.float32),
+             "b": jnp.zeros((512,), jnp.float32)}
+    trees = tm.tmap(lambda x: jnp.zeros((ell,) + x.shape, x.dtype), tree0)
+    width = min(x.size for x in jax.tree_util.tree_leaves(tree0))
+    closed = jax.make_jaxpr(lambda a, s: dp.dp_delta(a, s, 0.5))(tree0,
+                                                                  trees)
+    loops = list(_loops(closed.jaxpr))
+    assert loops, "the recurrence is a scan"
+    for eqn in loops:
+        sizes = [int(np.prod(v.aval.shape)) for v in eqn.invars]
+        assert max(sizes) < width, (eqn.primitive.name, sizes)
 
 
 def test_tree_math_basics():
