@@ -10,8 +10,13 @@ chunk-local so fp32 cumsums never grow with S (they would lose precision at
 500k tokens in a global-cumsum formulation). The sLSTM is inherently
 sequential (its recurrence is nonlinear), so it scans over time steps.
 
+The sLSTM scan carries its state in float32 whatever the compute dtype,
+as the mLSTM's does. Each recurrence runs under a ``jax.named_scope``
+(``slstm_scan``, ``mlstm_chunk``) that holds it alone, so a device trace
+can tell its time from the projections around it.
+
 Consistency between the chunkwise forward and the per-token decode step is
-asserted in tests/test_xlstm.py.
+asserted in tests/test_models_consistency.py.
 """
 from __future__ import annotations
 
@@ -167,8 +172,9 @@ def mlstm_forward(p, x, cfg: ModelConfig, chunk: int = 256,
         h, C, n, m = _mlstm_chunk(qc, kc, vc, ic, fc, C, n, m)
         return (C, n, m), h
 
-    (C, n, m), hs = jax.lax.scan(body, (st0.C, st0.n, st0.m),
-                                 (qs, ks, vs, is_, fs_))
+    with jax.named_scope("mlstm_chunk"):
+        (C, n, m), hs = jax.lax.scan(body, (st0.C, st0.n, st0.m),
+                                     (qs, ks, vs, is_, fs_))
     h = hs.swapaxes(0, 1).reshape(B, S, H, dh)
     h = group_norm_heads(h, p["gn_scale"].reshape(H, dh), cfg.norm_eps)
     y = (h.reshape(B, S, e) * jax.nn.silu(z)) @ p["w_down"]
@@ -284,19 +290,23 @@ def _slstm_out(p, h, cfg: ModelConfig):
 
 
 def slstm_forward(p, x, cfg: ModelConfig, return_cache: bool = False):
-    """Run the sLSTM over a full sequence via lax.scan over time."""
+    """Run the sLSTM over a full sequence via lax.scan over time.
+
+    The scan carries c, n, h and m in float32 (the conv tail stays out of
+    it); each step's h leaves the scan in x's dtype."""
     B, S, d = x.shape
     xc, conv_state = causal_conv1d(x, p["conv"])
     xc = jax.nn.silu(xc)
     wx = xc @ p["w"] + p["b"]
 
-    st0 = init_slstm_state(cfg, B, dtype=x.dtype)
+    st0 = init_slstm_state(cfg, B)._replace(conv=None)
 
     def body(st, wx_t):
         st = _slstm_cell(p, wx_t, st, cfg)
-        return st, st.h
+        return st, st.h.astype(x.dtype)
 
-    st, hs = jax.lax.scan(body, st0, wx.swapaxes(0, 1))
+    with jax.named_scope("slstm_scan"):
+        st, hs = jax.lax.scan(body, st0, wx.swapaxes(0, 1))
     h = hs.swapaxes(0, 1)                               # (B,S,d)
     y = _slstm_out(p, h, cfg)
     state = st._replace(conv=conv_state) if return_cache else None

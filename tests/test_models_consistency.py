@@ -125,3 +125,62 @@ def test_mlstm_chunk_size_invariance():
     y64, _ = mlstm_forward(p, x, cfg, chunk=64)
     np.testing.assert_allclose(np.asarray(y8), np.asarray(y64), rtol=1e-4,
                                atol=1e-4)
+
+
+def _slstm_block():
+    from repro.models.xlstm import init_slstm_params
+    cfg = configs.get_smoke("xlstm-125m")
+    p = init_slstm_params(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, cfg.d_model))
+    bf16 = lambda t: t.astype(jnp.bfloat16)
+    return cfg, p, x, jax.tree_util.tree_map(bf16, p), bf16(x)
+
+
+def test_slstm_scan_carries_float32_under_bf16():
+    """The sLSTM's c, n, h and m stay float32 through the time scan when
+    params and inputs are bf16; the per-step h leaves it in bf16."""
+    from repro.models.xlstm import slstm_forward
+    cfg, _, _, pb, xb = _slstm_block()
+    jaxpr = jax.make_jaxpr(lambda p, x: slstm_forward(p, x, cfg))(pb, xb)
+    [eqn] = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    carry = [v.aval for v in eqn.outvars[:eqn.params["num_carry"]]]
+    assert [a.dtype for a in carry] == [jnp.float32] * 4, carry
+    assert [a.shape for a in carry] == [(2, cfg.d_model)] * 4
+    assert eqn.outvars[-1].aval.dtype == jnp.bfloat16
+
+
+def test_bf16_slstm_block_stays_near_the_float32_one():
+    """At seq 128 the bf16 block's relative gap (norm of the difference over
+    the norm of the float32 output, at ``highest`` matmuls) reads 0.64%
+    with the float32 carry and 1.15% with a bf16 one. The limit, 0.9%,
+    sits between the two (near their geometric mean): a carry rounded to
+    bf16 fails it, the bf16 inputs, weights and matmuls alone pass."""
+    from repro.models.xlstm import slstm_forward
+    cfg, p, x, pb, xb = _slstm_block()
+    with jax.default_matmul_precision("highest"):
+        ref, _ = slstm_forward(p, x, cfg)
+    y, _ = jax.jit(lambda p, x: slstm_forward(p, x, cfg))(pb, xb)
+    assert y.dtype == jnp.bfloat16
+    gap = jnp.linalg.norm(y.astype(jnp.float32) - ref) / jnp.linalg.norm(ref)
+    assert float(gap) < 0.009, float(gap)
+
+
+@pytest.mark.parametrize("mixer,scope", [("slstm", "slstm_scan"),
+                                         ("mlstm", "mlstm_chunk")])
+def test_recurrence_alone_under_its_scope(mixer, scope):
+    """A mixer's named scope marks its scan, forward and transposed, and no
+    projection: a device trace reads the recurrence apart from the matmuls
+    around it."""
+    from repro.models import xlstm
+    cfg = configs.get_smoke("xlstm-125m")
+    init = getattr(xlstm, f"init_{mixer}_params")
+    fwd = getattr(xlstm, f"{mixer}_forward")
+    p = init(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: jnp.sum(fwd(p, x, cfg)[0])))(p)
+    marked = lambda e: scope in str(e.source_info.name_stack)  # noqa: E731
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    dots = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert len(scans) == 2 and all(map(marked, scans))
+    assert dots and not any(map(marked, dots))
