@@ -18,7 +18,8 @@ from repro.algorithms.base import (ClientResult, FedAlgorithm,
 from repro.core import tree_math as tm
 from repro.core.dp_delta import (dp_delta, online_dp_delta, online_dp_init,
                                  online_dp_update)
-from repro.core.iasg import iasg_sample, sample_window, sgd_steps
+from repro.core.iasg import (compute_copy, iasg_sample, local_steps,
+                             sample_window)
 from repro.optim import Optimizer
 
 
@@ -129,11 +130,12 @@ class FedPA(FedAlgorithm):
         def update(params, batches):
             opt_state = client_opt.init(params)
             split = lambda tree, a, b: tm.tmap(lambda x: x[a:b], tree)
-            p, s = params, opt_state
+            p, cp, s = params, compute_copy(grad_fn, params), opt_state
             loss_first = None
             if fed.burn_in_steps:
-                p, s, burn = sgd_steps(p, client_opt, s, grad_fn,
-                                       split(batches, 0, fed.burn_in_steps))
+                p, cp, s, burn = local_steps(
+                    p, cp, s, client_opt, grad_fn,
+                    split(batches, 0, fed.burn_in_steps))
                 loss_first = burn[0]
             windows = tm.tmap(
                 lambda x: x[fed.burn_in_steps:].reshape(
@@ -144,16 +146,17 @@ class FedPA(FedAlgorithm):
                                  dtype=delta_dtype)
 
             def window(carry, wb):
-                p, s, dp = carry
+                p, cp, s, dp = carry
                 # The IASG sample space IS delta_dtype by contract, as in
                 # the batch path; the fp32 accumulation happens downstream
                 # in the Sherman-Morrison online-DP state.
-                p, s, sample, losses = sample_window(p, s, client_opt,
-                                                     grad_fn, wb, delta_dtype)
+                p, cp, s, sample, losses = sample_window(
+                    p, cp, s, client_opt, grad_fn, wb, delta_dtype)
                 dp = online_dp_update(dp, sample, rho)
-                return (p, s, dp), losses
+                return (p, cp, s, dp), losses
 
-            (p, s, dp), losses = jax.lax.scan(window, (p, s, dp0), windows)
+            (p, cp, s, dp), losses = jax.lax.scan(window, (p, cp, s, dp0),
+                                                  windows)
             delta = tm.tcast(online_dp_delta(dp, rho), delta_dtype)
             first = loss_first if loss_first is not None else losses[0, 0]
             return ClientResult(delta, {"loss_first": first,

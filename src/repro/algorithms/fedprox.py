@@ -10,6 +10,7 @@ as a pure client-side override.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 from repro.algorithms.base import (ClientResult, FedAlgorithm,
@@ -38,8 +39,9 @@ class FedProx(FedAlgorithm):
         delta_dtype = self.delta_dtype
 
         def update(params, batches):
-            def prox_grad_fn(p, batch):
-                loss, grads = grad_fn(p, batch)
+            @functools.wraps(grad_fn)
+            def prox_grad_fn(p, batch, *cparams):
+                loss, grads = grad_fn(p, batch, *cparams)
                 grads = tm.tmap(
                     lambda g, pi, p0: g + (mu * (pi - p0)).astype(g.dtype),
                     grads, p, params)

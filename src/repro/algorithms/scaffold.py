@@ -27,6 +27,7 @@ instead of a second gradient pass.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -95,8 +96,9 @@ class Scaffold(FedAlgorithm):
         delta_dtype = self.delta_dtype
 
         def update(params, batches, c_i, c):
-            def corrected_grad(p, batch):
-                loss, g = grad_fn(p, batch)
+            @functools.wraps(grad_fn)
+            def corrected_grad(p, batch, *cparams):
+                loss, g = grad_fn(p, batch, *cparams)
                 g = tm.tmap(
                     lambda gi, cs, ci: gi + (cs - ci).astype(gi.dtype),
                     g, c, c_i)

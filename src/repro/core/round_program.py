@@ -34,6 +34,7 @@ to floating-point reduction order (tests/test_round_engine.py).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -106,7 +107,8 @@ def _budget_masked(grad_fn: Callable) -> Callable:
     budget mask rides in the batch dict as the "_active" leaf, (C, K)
     alongside the data's (C, K, ...) leaves — data/cohort_source.py
     injects it."""
-    def masked_grad_fn(params, batch):
+    @functools.wraps(grad_fn)
+    def masked_grad_fn(params, batch, *cparams):
         if not isinstance(batch, dict) or "_active" not in batch:
             raise ValueError(
                 "min_local_steps > 0 needs dict batches carrying the "
@@ -114,7 +116,7 @@ def _budget_masked(grad_fn: Callable) -> Callable:
                 "(data/cohort_source.py injects it)")
         active = jnp.asarray(batch["_active"], jnp.float32)
         data = {k: v for k, v in batch.items() if k != "_active"}
-        loss, grads = grad_fn(params, data)
+        loss, grads = grad_fn(params, data, *cparams)
         return loss, tm.tmap(lambda g: g * active.astype(g.dtype), grads)
 
     return masked_grad_fn

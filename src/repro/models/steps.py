@@ -31,20 +31,53 @@ def lm_loss(params, batch, cfg: ModelConfig, *, compute_dtype=jnp.bfloat16,
     return total, {"xent": xent, "moe_aux": aux}
 
 
-def lm_grad_fn(cfg: ModelConfig, **kw):
+def lm_grad_fn(cfg: ModelConfig, *, compute_dtype=jnp.bfloat16, **kw):
     """The (loss, grads) client gradient function FedAvg/FedPA scan over.
 
     The loss is traced under ``jax.named_scope("client_grad")``, inside the
     differentiated function, so the backward pass's ops carry the scope
-    under ``transpose(...)`` and the forward's without it."""
+    under ``transpose(...)`` and the forward's without it.
+
+    ``fn(params, batch, cparams=None)``: ``cparams``, where given, is
+    ``params`` cast to ``fn.compute_dtype``, made by the caller
+    (``core.iasg.compute_copy``). The gradient is then taken at it, so the
+    forward's cast is a no-op, and returned in the params' dtype: the same
+    values as the gradient at ``params``, which goes through that cast."""
     def loss_fn(params, batch):
         with jax.named_scope("client_grad"):
-            return lm_loss(params, batch, cfg, **kw)
+            return lm_loss(params, batch, cfg, compute_dtype=compute_dtype,
+                           **kw)
 
-    def fn(params, batch):
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, batch)
+    def fn(params, batch, cparams=None):
+        if cparams is None:
+            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, batch)
+            return loss, grads
+        # A tied head reads the embedding through a leaf of its own, so the
+        # gather's and the head's gradients reach float32 apart and are
+        # summed there, as when the forward casts: XLA then drops the bf16
+        # rounding of their sum
+        head = None if "unembed" in cparams else cparams["embed"]
+
+        def split_loss(cp, head):
+            return loss_fn(cp if head is None else dict(cp, unembed=head.T),
+                           batch)
+
+        (loss, _), (grads, head) = jax.value_and_grad(
+            split_loss, argnums=(0, 1), has_aux=True)(cparams, head)
+        # XLA moves a widening convert above a reshape it then cannot fuse
+        # across (the embedding's batched scatter ends flattened): the
+        # barrier keeps each convert on the gradient's own shape, where it
+        # fuses into the optimizer's update
+        grads, head = jax.lax.optimization_barrier((grads, head))
+        grads = jax.tree_util.tree_map(lambda g, p: g.astype(p.dtype), grads,
+                                       params)
+        if head is not None:
+            grads["embed"] = grads["embed"] + head.astype(
+                grads["embed"].dtype)
         return loss, grads
+
+    fn.compute_dtype = jnp.dtype(compute_dtype)
     return fn
 
 

@@ -1,12 +1,15 @@
 """Compile for a described TPU v5e chip (none attached): the Pallas kernels
-at real widths and the full-width fedlm-100m rounds that ``chip_smoke.py``
-runs, so a kernel or a program the chip's compiler refuses fails here.
+at real widths, the full-width fedlm-100m rounds that ``chip_smoke.py``
+runs and the benchmark cells' rounds, so a kernel or a program the chip's
+compiler refuses fails here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and xdist workers import every
 test file."""
 import contextlib
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +24,11 @@ from repro.core.sharded_round import make_fed_round
 from repro.kernels import fedpa_dp, ops
 from repro.models import init_params
 from repro.optim import get_optimizer
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+from bench import casts, scopes  # noqa: E402
 
 #: flattened fedlm-100m
 D_FEDLM = 100_684_032
@@ -87,25 +95,26 @@ def test_swa_decode_compiles_at_gemma3_window(one_chip):
 C, K, B, S = 5, 8, 4, 128
 _SCAFFOLD = dict(algorithm="scaffold", client_opt="sgd",
                  client_state_placement="device")
+_FEDPA = dict(algorithm="fedpa", burn_in_steps=4, steps_per_sample=2,
+              client_opt="sgdm")
 #: name -> (clients, compute dtype, FedConfig fields)
 ROUNDS = {
-    "fedpa": (C, jnp.bfloat16, dict(algorithm="fedpa", burn_in_steps=4,
-                                    steps_per_sample=2, client_opt="sgdm")),
+    "fedpa": (C, jnp.bfloat16, _FEDPA),
+    "fedavg": (C, jnp.bfloat16, dict(algorithm="fedavg", client_opt="sgdm")),
     "scaffold_device_store": (C, jnp.bfloat16, _SCAFFOLD),
     # the one-chip side of ``chip_smoke.py --chips 4``'s fp32 comparison
     "scaffold_device_store_fp32": (4, jnp.float32, _SCAFFOLD),
 }
 
 
-@pytest.mark.parametrize("name", list(ROUNDS))
-def test_fedlm_round_fits_one_chip(one_chip, name):
-    C, dtype, fields = ROUNDS[name]
-    cfg = configs.get_config("fedlm-100m")
+def _compile_round(one_chip, arch, C, dtype, fields):
+    """The parallel placement's round program of ``arch`` at full width,
+    compiled for the described chip."""
+    cfg = configs.get_config(arch)
     fed = FedConfig(clients_per_round=C, local_steps=K, server_opt="sgdm",
                     server_lr=0.5, client_lr=0.05, **fields)
     alg = get_algorithm(fed)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == D_FEDLM
     opt = get_optimizer(fed.server_opt, fed.server_lr, fed.server_momentum)
     state = jax.tree_util.tree_map(
         lambda x: _spec(one_chip, x.shape, x.dtype),
@@ -131,11 +140,52 @@ def test_fedlm_round_fits_one_chip(one_chip, name):
                 _spec(one_chip, (C,), jnp.int32), None)
         else:
             lowered = jax.jit(round_fn).lower(state, batches, None, None)
-        mem = lowered.compile().memory_analysis()
-    if alg.stateful:
-        # the donated store is updated in place
-        assert mem.alias_size_in_bytes >= 8 * 4 * D_FEDLM
+        return lowered.compile()
+
+
+def _update_bodies(hlo_text):
+    """The computations that run the client optimizer's update or the IASG
+    sum among their kernels: the local-step loops' bodies."""
+    names = scopes.op_names(hlo_text)
+    comps = casts.computations(hlo_text)
+    fused = {calls for body in comps.values() for *_, calls in body if calls}
+    return [comp for comp, body in comps.items() if comp not in fused
+            and any(scopes.scope_of(names.get(instr, "")) in
+                    ("client_opt", "iasg_average") for instr, *_ in body)]
+
+
+def _check_round(compiled, steps_loops):
+    """The compiled peak fits the chip, and no local-step loop casts the
+    params: the optimizer's update writes their compute copy, and only the
+    round's entry casts the broadcast params."""
+    text = compiled.as_text()
+    bodies = _update_bodies(text)
+    assert len(bodies) == steps_loops, bodies
+    found = casts.casts(text)
+    assert all(found[body] == [] for body in bodies), \
+        {body: found[body] for body in bodies}
+    mem = compiled.memory_analysis()
     # the compiler's own peak; temp_size_in_bytes counts buffers that are
     # never live together (17.2 GB of temp for a 12.5 GB peak at C=4)
-    assert mem.peak_memory_in_bytes < HBM_LIMIT, (name,
-                                                  mem.peak_memory_in_bytes)
+    assert mem.peak_memory_in_bytes < HBM_LIMIT, mem.peak_memory_in_bytes
+    return mem
+
+
+@pytest.mark.parametrize("name", list(ROUNDS))
+def test_fedlm_round_fits_one_chip(one_chip, name):
+    C, dtype, fields = ROUNDS[name]
+    params = jax.eval_shape(lambda: init_params(
+        jax.random.PRNGKey(0), configs.get_config("fedlm-100m")))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == D_FEDLM
+    compiled = _compile_round(one_chip, "fedlm-100m", C, dtype, fields)
+    # fedpa's burn-in and sampling steps are two loops
+    mem = _check_round(compiled, 2 if fields["algorithm"] == "fedpa" else 1)
+    if fields["algorithm"] == "scaffold":
+        # the donated store is updated in place
+        assert mem.alias_size_in_bytes >= 8 * 4 * D_FEDLM
+
+
+def test_xlstm_round_fits_one_chip(one_chip):
+    """The xlstm125m-fedpa cell's round: FedPA at 4 clients."""
+    _check_round(_compile_round(one_chip, "xlstm-125m", 4, jnp.bfloat16,
+                                _FEDPA), 2)
